@@ -6,7 +6,9 @@ Every metric is exposed in two forms:
     pairwise function, pre-normalisation and Hilbert-embeddability flag.
 
 All pairwise computations accumulate in float32 (or float64 if enabled) even for
-bf16 inputs; matmul-shaped paths use ``preferred_element_type``.
+bf16 inputs; matmul-shaped paths use ``preferred_element_type`` and
+``Precision.HIGHEST`` — a TPU's default single bf16 pass would swamp the
+small differences of large norms that every expanded distance subtracts.
 """
 from __future__ import annotations
 
@@ -30,7 +32,8 @@ def sqeuclidean_pdist(X: Array, Y: Array) -> Array:
     acc = _acc_dtype(X)
     x2 = jnp.sum(X.astype(acc) ** 2, axis=-1)
     y2 = jnp.sum(Y.astype(acc) ** 2, axis=-1)
-    xy = jnp.matmul(X, Y.T, preferred_element_type=acc)
+    xy = jnp.matmul(X, Y.T, preferred_element_type=acc,
+                    precision=jax.lax.Precision.HIGHEST)
     d2 = x2[:, None] + y2[None, :] - 2.0 * xy
     if Y is X:
         # self-distances are definitionally zero; the matmul form leaves
@@ -105,11 +108,14 @@ def qform_pdist(X: Array, Y: Array, M: Array) -> Array:
     D(v,w)^2 = v'Mv + w'Mw - 2 v'Mw : three matmuls, no N*M*m intermediate.
     """
     acc = _acc_dtype(X)
-    XM = jnp.matmul(X, M, preferred_element_type=acc)
-    YM = XM if Y is X else jnp.matmul(Y, M, preferred_element_type=acc)
+    XM = jnp.matmul(X, M, preferred_element_type=acc,
+                    precision=jax.lax.Precision.HIGHEST)
+    YM = XM if Y is X else jnp.matmul(
+        Y, M, preferred_element_type=acc, precision=jax.lax.Precision.HIGHEST)
     xmx = jnp.sum(XM * X, axis=-1)
     ymy = xmx if Y is X else jnp.sum(YM * Y, axis=-1)
-    xmy = jnp.matmul(XM, Y.T, preferred_element_type=acc)
+    xmy = jnp.matmul(XM, Y.T, preferred_element_type=acc,
+                     precision=jax.lax.Precision.HIGHEST)
     d2 = xmx[:, None] + ymy[None, :] - 2.0 * xmy
     if Y is X:  # exact-zero self distances (cf. sqeuclidean_pdist)
         d2 = d2 * (1.0 - jnp.eye(d2.shape[0], dtype=d2.dtype))

@@ -200,7 +200,7 @@ def verify_base_simplex(D: Array, base: BaseSimplex, *, atol: float = 1e-4) -> T
     d2 = (
         jnp.sum(V**2, -1)[:, None]
         + jnp.sum(V**2, -1)[None, :]
-        - 2 * V @ V.T
+        - 2 * jnp.matmul(V, V.T, precision=jax.lax.Precision.HIGHEST)
     )
     # self-distances are definitionally zero; the matrix-op form leaves
     # O(eps*||v||^2) roundoff there which sqrt would inflate to O(sqrt(eps))

@@ -45,7 +45,8 @@ def estimate_pdist(X: Array, Y: Array, mode: str = "zen") -> Array:
     Xa, Ya = X.astype(acc), Y.astype(acc)
     nx = jnp.sum(Xa * Xa, axis=-1)
     ny = jnp.sum(Ya * Ya, axis=-1)
-    p = jnp.matmul(Xa[:, :-1], Ya[:, :-1].T, preferred_element_type=acc)
+    p = jnp.matmul(Xa[:, :-1], Ya[:, :-1].T, preferred_element_type=acc,
+                   precision=jax.lax.Precision.HIGHEST)
     z2 = nx[:, None] + ny[None, :] - 2.0 * p
     if mode != "zen":
         cross = jnp.outer(Xa[:, -1], Ya[:, -1])
@@ -71,7 +72,8 @@ def estimate_triple(X: Array, Y: Array) -> Tuple[Array, Array, Array]:
     Xa, Ya = X.astype(acc), Y.astype(acc)
     nx = jnp.sum(Xa * Xa, axis=-1)
     ny = jnp.sum(Ya * Ya, axis=-1)
-    p = jnp.matmul(Xa[:, :-1], Ya[:, :-1].T, preferred_element_type=acc)
+    p = jnp.matmul(Xa[:, :-1], Ya[:, :-1].T, preferred_element_type=acc,
+                   precision=jax.lax.Precision.HIGHEST)
     z2 = nx[:, None] + ny[None, :] - 2.0 * p
     cross = 2.0 * jnp.outer(Xa[:, -1], Ya[:, -1])
     sq = lambda a: jnp.sqrt(jnp.maximum(a, 0.0))

@@ -39,11 +39,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # moved out of experimental in newer jax
-    from jax.shard_map import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
-
 from repro.kernels import ops as kernel_ops
 
 Array = jax.Array
@@ -223,13 +218,13 @@ def _sharded_topk(
         in_specs.append(P(shard_axes))
         operands.append(alive)
     # the ring leaves every device holding the same merged top-k, so the
-    # outputs are replicated (check_rep can't prove it through ppermute)
-    return shard_map(
+    # outputs are replicated (check_vma can't prove it through ppermute)
+    return jax.shard_map(
         local_topk,
         mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(*operands)
 
 
@@ -378,10 +373,10 @@ def _sharded_ivf_topk(
     if alive is not None:
         in_specs.append(P(shard_axes))
         operands.append(alive)
-    return shard_map(
+    return jax.shard_map(
         local_probe,
         mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(*operands)

@@ -33,7 +33,8 @@ def _sq_dist(blk: Array, centroids: Array) -> Array:
     """Squared Euclidean distances (rows, C) between blk and centroids, f32."""
     bn = jnp.sum(blk * blk, axis=1, keepdims=True)
     cn = jnp.sum(centroids * centroids, axis=1)
-    dot = jnp.matmul(blk, centroids.T, preferred_element_type=jnp.float32)
+    dot = jnp.matmul(blk, centroids.T, preferred_element_type=jnp.float32,
+                     precision=jax.lax.Precision.HIGHEST)
     return jnp.maximum(bn + cn[None, :] - 2.0 * dot, 0.0)
 
 

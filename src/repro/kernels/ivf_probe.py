@@ -16,12 +16,18 @@ index maps read ``probes[i, j // T] * T + j % T`` to DMA exactly the probed
 tiles from HBM — un-probed clusters are never touched, which is what makes
 the probe sublinear in index size. Each grid step fuses the Zen/Lwb/Upb
 estimator over one tile (``kernels.scoring.estimate_tile`` — shared with the
-brute-force ``zen_topk`` kernel) with the concat + ``top_k`` merge into VMEM
-scratch; dead rows (id == -1: tile padding *and* tombstoned deletes — the
-mutable-index path reuses the same encoding, ``kernels.scoring.mask_invalid``)
-are masked to +inf before the merge. Peak
-per-query state is O(kw + tile_rows), independent of both index size and
-cluster-size skew.
+brute-force ``zen_topk`` kernel) with the lane-min merge
+(``kernels.scoring.merge_topk_rounds``) into VMEM scratch; dead rows
+(id == -1: tile padding *and* tombstoned deletes — the mutable-index path
+reuses the same encoding, ``kernels.scoring.mask_invalid``) are masked to
++inf before the merge. Peak per-query state is O(kw + tile_rows),
+independent of both index size and cluster-size skew.
+
+Mosaic accepts a block only when its last two dims divide by (8, 128) or
+equal the array's, so every per-query operand is a (n, 1, X) view with a
+squeezed leading block dim, and the tiles are read as (C*T, k, tile_rows):
+rows on lanes, k on sublanes — a layout bitcast of the stored tiles on TPU,
+with no lane padding of a narrow k.
 
 ``ivf_probe_scan`` is the schedule-equivalent jnp fallback for CPU/GPU: a
 ``fori_loop`` over the same (probe, tile) steps, gathering one
@@ -37,10 +43,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import compiler_params
 from .scoring import (
     MODE_IDS, estimate_rows, estimate_tile, lut_estimate_rows,
-    lut_estimate_tile, mask_invalid, merge_topk,
+    lut_estimate_tile, mask_invalid, merge_topk, merge_topk_rounds,
 )
 
 Array = jax.Array
@@ -48,12 +53,12 @@ Array = jax.Array
 
 def _probe_kernel(
     probes_ref,  # scalar-prefetch (Q, P) — also consumed by the index maps
-    q_ref,       # (1, kp)
-    x_ref,       # (1, tile_rows, kp) — the probed tile
+    q_ref,       # (1, k)
+    x_ref,       # (k, tile_rows) — the probed tile, rows on lanes
     id_ref,      # (1, tile_rows)
     *rest,       # [s_ref (1, 1)] od_ref oi_ref + scratch bd_ref bi_ref
-    true_k: int,
     n_steps: int,
+    n_keep: int,
     mode: int,
     has_scale: bool,
 ):
@@ -70,21 +75,37 @@ def _probe_kernel(
         bd_ref[...] = jnp.full_like(bd_ref, jnp.inf)
         bi_ref[...] = jnp.full_like(bi_ref, -1)
 
-    q = q_ref[...].astype(jnp.float32)          # (1, kp)
-    x = x_ref[0].astype(jnp.float32)            # (tile_rows, kp)
+    q = q_ref[...].astype(jnp.float32)          # (1, k)
+    xt = x_ref[...].astype(jnp.float32)         # (k, tile_rows)
     ids = id_ref[...]                           # (1, tile_rows)
-    scale = s_ref[0, 0] if has_scale else None
-    d = estimate_tile(
-        q, x, true_k=true_k, mode=mode, scale=scale)  # (1, tile_rows)
+    scale = s_ref[...] if has_scale else None   # (1, 1)
+    d = estimate_tile(q, xt, mode=mode, scale=scale)  # (1, tile_rows)
     d = mask_invalid(d, ids)                    # padding + tombstones
 
-    kw = bd_ref.shape[1]
-    bd_ref[...], bi_ref[...] = merge_topk(bd_ref[...], bi_ref[...], d, ids, kw)
+    bd_ref[...], bi_ref[...] = merge_topk_rounds(
+        bd_ref[...], bi_ref[...], d, ids, n_keep)
 
     @pl.when(j == n_steps - 1)
     def _done():
         od_ref[...] = bd_ref[...]
         oi_ref[...] = bi_ref[...]
+
+
+def _row_spec(width: int) -> pl.BlockSpec:
+    """One query's (1, width) row of a (Q, 1, width) view, per grid row."""
+    return pl.BlockSpec((None, 1, width), lambda i, j, pref: (i, 0, 0))
+
+
+def _probe_outputs(q: int, kw: int):
+    """(out_specs, scratch_shapes, out_shape) of a per-query probe: the
+    (Q, 1, kw) output views keep every block's last two dims equal to the
+    array's."""
+    return (
+        [_row_spec(kw), _row_spec(kw)],
+        [pltpu.VMEM((1, kw), jnp.float32), pltpu.VMEM((1, kw), jnp.int32)],
+        [jax.ShapeDtypeStruct((q, 1, kw), jnp.float32),
+         jax.ShapeDtypeStruct((q, 1, kw), jnp.int32)],
+    )
 
 
 @functools.partial(
@@ -108,7 +129,8 @@ def ivf_probe(
     Args:
       queries:     (Q, k) projected queries.
       tile_coords: (C*T, tile_rows, k) packed cluster tiles — stored f32,
-                   bf16 or int8 (``kernels.quantize``).
+                   bf16 or int8 (``kernels.quantize``). The kernel reads
+                   them as (C*T, k, tile_rows), rows on lanes.
       tile_ids:    (C*T, tile_rows) int32 global row ids, -1 = padding.
       probes:      (Q, P) int32 cluster ids to visit per query.
       tiles_per_cluster: T — tiles per cluster in the packed layout.
@@ -128,62 +150,46 @@ def ivf_probe(
     assert probes.shape[0] == q, (probes.shape, queries.shape)
     assert ct % tiles_per_cluster == 0, (ct, tiles_per_cluster)
     T = tiles_per_cluster
-    n_probe = probes.shape[1]
-    n_steps = n_probe * T
+    n_steps = probes.shape[1] * T
     kw = _rup(n_neighbors, 128)  # scratch lane width
-    Kp = _rup(kdim, 128)
-    Qpad = jnp.pad(queries, ((0, 0), (0, Kp - kdim)))
-    Xpad = jnp.pad(tile_coords, ((0, 0), (0, 0), (0, Kp - kdim)))
+
+    def tile(i, j, pref):
+        return (pref[i, j // T] * T + j % T, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, Kp), lambda i, j, pref: (i, 0)),
-        pl.BlockSpec(
-            (1, tile_rows, Kp),
-            lambda i, j, pref: (pref[i, j // T] * T + j % T, 0, 0),
-        ),
-        pl.BlockSpec(
-            (1, tile_rows),
-            lambda i, j, pref: (pref[i, j // T] * T + j % T, 0),
-        ),
+        _row_spec(kdim),
+        pl.BlockSpec((None, kdim, tile_rows), tile),
+        pl.BlockSpec((None, 1, tile_rows), tile),
     ]
-    operands = [Qpad, Xpad, tile_ids]
+    operands = [
+        queries.reshape(q, 1, kdim),
+        jnp.swapaxes(tile_coords, 1, 2),
+        tile_ids.reshape(ct, 1, tile_rows),
+    ]
     if tile_scales is not None:
         assert tile_scales.shape == (ct // T, 1), (tile_scales.shape, ct, T)
         # the probed *cluster* id indexes the scales directly
         in_specs.append(pl.BlockSpec(
-            (1, 1), lambda i, j, pref: (pref[i, j // T], 0)))
-        operands.append(tile_scales.astype(jnp.float32))
+            (None, 1, 1), lambda i, j, pref: (pref[i, j // T], 0, 0)))
+        operands.append(tile_scales.astype(jnp.float32).reshape(ct // T, 1, 1))
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(q, n_steps),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, kw), lambda i, j, pref: (i, 0)),
-            pl.BlockSpec((1, kw), lambda i, j, pref: (i, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, kw), jnp.float32),
-            pltpu.VMEM((1, kw), jnp.int32),
-        ],
-    )
+    out_specs, scratch, out_shape = _probe_outputs(q, kw)
     out_d, out_i = pl.pallas_call(
         functools.partial(
-            _probe_kernel, true_k=kdim, n_steps=n_steps, mode=MODE_IDS[mode],
-            has_scale=tile_scales is not None,
+            _probe_kernel, n_steps=n_steps, n_keep=n_neighbors,
+            mode=MODE_IDS[mode], has_scale=tile_scales is not None,
         ),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((q, kw), jnp.float32),
-            jax.ShapeDtypeStruct((q, kw), jnp.int32),
-        ],
-        compiler_params=compiler_params(
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(q, n_steps), in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
         name="nsimplex_ivf_probe",
     )(probes.astype(jnp.int32), *operands)
-    return out_d[:, :n_neighbors], out_i[:, :n_neighbors]
+    return out_d[:, 0, :n_neighbors], out_i[:, 0, :n_neighbors]
 
 
 @functools.partial(
@@ -257,8 +263,8 @@ def _rup(x: int, mult: int) -> int:
 
 def _probe_pq_kernel(
     probes_ref,  # scalar-prefetch (Q, P)
-    lut_ref,     # (1, M, E) — this (query, probe column)'s ADC table
-    x_ref,       # (1, tile_rows, M) uint8 — the probed code tile
+    lut_ref,     # (M, E) — this (query, probe column)'s ADC table
+    x_ref,       # (M, tile_rows) int32 — the probed code tile, transposed
     id_ref,      # (1, tile_rows)
     od_ref,
     oi_ref,
@@ -266,6 +272,7 @@ def _probe_pq_kernel(
     bi_ref,      # scratch (1, kw) int32
     *,
     n_steps: int,
+    n_keep: int,
 ):
     del probes_ref  # only the index maps need it
     j = pl.program_id(1)
@@ -275,13 +282,12 @@ def _probe_pq_kernel(
         bd_ref[...] = jnp.full_like(bd_ref, jnp.inf)
         bi_ref[...] = jnp.full_like(bi_ref, -1)
 
-    codes = x_ref[0]                             # (tile_rows, M) uint8
     ids = id_ref[...]                            # (1, tile_rows)
-    d = lut_estimate_tile(lut_ref[0], codes)     # (1, tile_rows)
+    d = lut_estimate_tile(lut_ref[...], x_ref[...])  # (1, tile_rows)
     d = mask_invalid(d, ids)                     # padding + tombstones
 
-    kw = bd_ref.shape[1]
-    bd_ref[...], bi_ref[...] = merge_topk(bd_ref[...], bi_ref[...], d, ids, kw)
+    bd_ref[...], bi_ref[...] = merge_topk_rounds(
+        bd_ref[...], bi_ref[...], d, ids, n_keep)
 
     @pl.when(j == n_steps - 1)
     def _done():
@@ -308,7 +314,9 @@ def ivf_probe_pq(
     Args:
       tile_codes: (C*T, tile_rows, M) uint8 packed member codes
                   (``kernels.pq``); cluster ``c`` owns blocks
-                  ``c*T .. c*T+T-1`` exactly like the scalar layout.
+                  ``c*T .. c*T+T-1`` exactly like the scalar layout. The
+                  kernel reads them widened to int32 and transposed,
+                  (C*T, M, tile_rows): Mosaic has no uint8 vector compare.
       tile_ids:   (C*T, tile_rows) int32 global row ids, -1 = padding.
       probes:     (Q, P) int32 cluster ids to visit per query.
       luts:       (Q, P, M, E) f32 ADC tables (``pq.build_luts``) — the
@@ -337,44 +345,35 @@ def ivf_probe_pq(
     # index keep the block maps rank-uniform for Mosaic
     luts3 = luts.astype(jnp.float32).reshape(q * n_probe, m, e)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(q, n_steps),
-        in_specs=[
-            pl.BlockSpec(
-                (1, m, e), lambda i, j, pref: (i * n_probe + j // T, 0, 0)),
-            pl.BlockSpec(
-                (1, tile_rows, m),
-                lambda i, j, pref: (pref[i, j // T] * T + j % T, 0, 0),
-            ),
-            pl.BlockSpec(
-                (1, tile_rows),
-                lambda i, j, pref: (pref[i, j // T] * T + j % T, 0),
-            ),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, kw), lambda i, j, pref: (i, 0)),
-            pl.BlockSpec((1, kw), lambda i, j, pref: (i, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, kw), jnp.float32),
-            pltpu.VMEM((1, kw), jnp.int32),
-        ],
-    )
+    def tile(i, j, pref):
+        return (pref[i, j // T] * T + j % T, 0, 0)
+
+    out_specs, scratch, out_shape = _probe_outputs(q, kw)
     out_d, out_i = pl.pallas_call(
-        functools.partial(_probe_pq_kernel, n_steps=n_steps),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((q, kw), jnp.float32),
-            jax.ShapeDtypeStruct((q, kw), jnp.int32),
-        ],
-        compiler_params=compiler_params(
+        functools.partial(_probe_pq_kernel, n_steps=n_steps,
+                          n_keep=n_neighbors),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(q, n_steps),
+            in_specs=[
+                pl.BlockSpec((None, m, e),
+                             lambda i, j, pref: (i * n_probe + j // T, 0, 0)),
+                pl.BlockSpec((None, m, tile_rows), tile),
+                pl.BlockSpec((None, 1, tile_rows), tile),
+            ],
+            out_specs=out_specs,
+            scratch_shapes=scratch,
+        ),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
         name="nsimplex_ivf_probe_pq",
-    )(probes.astype(jnp.int32), luts3, tile_codes, tile_ids)
-    return out_d[:, :n_neighbors], out_i[:, :n_neighbors]
+    )(probes.astype(jnp.int32), luts3,
+      jnp.swapaxes(tile_codes, 1, 2).astype(jnp.int32),
+      tile_ids.reshape(ct, 1, tile_rows))
+    return out_d[:, 0, :n_neighbors], out_i[:, 0, :n_neighbors]
 
 
 @functools.partial(

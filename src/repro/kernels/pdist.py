@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import compiler_params
 
 Array = jax.Array
 
@@ -83,7 +82,7 @@ def pdist_sq(
         out_specs=pl.BlockSpec((bn, bk), lambda i, j, l: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Np, Kp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bn, bk), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

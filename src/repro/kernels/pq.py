@@ -193,7 +193,8 @@ def build_luts(
     rn = jnp.sum(r * r, axis=-1)                     # (Q, P, M)
     cn = jnp.sum(cb * cb, axis=-1)                   # (M, E)
     dot = jnp.einsum("qpmd,med->qpme", r, cb,
-                     preferred_element_type=jnp.float32)
+                     preferred_element_type=jnp.float32,
+                     precision=jax.lax.Precision.HIGHEST)
     lut = rn[..., None] + cn[None, None] - 2.0 * dot  # (Q, P, M, E)
     # the base table is the plain squared Euclidean ||q - x_hat||^2, which
     # IS the Lwb estimator (paper §4.1: lwb^2 = sum_i<alt (q_i - x_i)^2 +

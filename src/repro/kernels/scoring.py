@@ -6,14 +6,17 @@ tiles — fuse the same two inner loops:
 
   1. estimator distances between a query block and one index tile
      (masked-last-column matmul + rank-1 altitude correction, paper §4.1);
-  2. a merge of that tile's distances into a running per-query best-k
-     (concat + ``lax.top_k``), kept in VMEM scratch on TPU.
+  2. a merge of that tile's distances into a running per-query best-k,
+     kept in VMEM scratch on TPU.
 
 This module is that shared inner loop, factored out so the two kernels (and
 their jnp scan fallbacks) cannot drift apart numerically. ``estimate_tile``
-operates on lane-padded 2D tiles as seen inside a Pallas kernel body;
+operates on transposed (k, rows) tiles as seen inside a Pallas kernel body;
 ``estimate_rows`` is the batched-gather variant used by the IVF scan fallback
-where every query gathers its *own* (rows, k) tile.
+where every query gathers its *own* (rows, k) tile. The scans merge with
+``merge_topk`` (concat + ``lax.top_k``); Mosaic cannot lower ``top_k``, so
+the kernels merge with ``merge_topk_rounds``, which selects exactly the same
+entries in the same order.
 
 Both accept an optional ``scale`` for quantised index tiles
 (``kernels.quantize``): the tile is multiplied by its symmetric int8 scale
@@ -36,39 +39,40 @@ MODE_IDS = {"zen": 0, "lwb": 1, "upb": 2}
 
 
 def estimate_tile(
-    q: Array, x: Array, *, true_k: int, mode: int,
-    scale: Optional[Array] = None,
+    q: Array, xt: Array, *, mode: int, scale: Optional[Array] = None,
 ) -> Array:
-    """Fused estimator distances for one (bq, kp) x (bn, kp) tile, f32.
+    """Fused estimator distances for one (bq, k) x (k, bn) tile, f32.
 
-    ``kp`` may be lane-padded beyond the true coordinate width ``true_k``;
-    padding columns and the altitude column are masked in-register. ``mode``
-    is the static id from :data:`MODE_IDS`. ``scale`` (scalar or (bn, 1),
-    broadcastable over ``x``) dequantises an int8 tile on the fly; ``x``
-    must already be cast to f32 by the caller in that case.
+    The index tile arrives *transposed* — coordinates on sublanes, rows on
+    lanes — so a k=16 tile is lane-dense and the estimator is one plain
+    (bq, k) @ (k, bn) matmul. ``mode`` is the static id from
+    :data:`MODE_IDS`. ``scale`` (scalar or (1, bn), broadcastable over
+    ``xt``) dequantises an int8 tile on the fly; ``xt`` must already be cast
+    to f32 by the caller in that case. The matmul runs at
+    ``Precision.HIGHEST``: the estimator subtracts two large norms, so a
+    single bf16 pass would swamp near-neighbour distances.
     """
     if scale is not None:
-        x = x * scale
-    kp = q.shape[1]
-    col = jax.lax.broadcasted_iota(jnp.int32, (1, kp), 1)
-    keep = (col < true_k - 1).astype(jnp.float32)  # mask altitude + padding
-    valid = (col < true_k).astype(jnp.float32)  # mask padding only
-    qv = q * valid
-    xv = x * valid
-    nq = jnp.sum(qv * qv, axis=1, keepdims=True)  # (bq, 1) full norms
-    nx = jnp.sum(xv * xv, axis=1, keepdims=True)  # (bn, 1)
+        xt = xt * scale
+    k = q.shape[1]
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+    keep = (col < k - 1).astype(jnp.float32)  # drop the altitude column
+    nq = jnp.sum(q * q, axis=1, keepdims=True)  # (bq, 1) full norms
+    nx = jnp.sum(xt * xt, axis=0, keepdims=True)  # (1, bn)
     dot = jax.lax.dot_general(
-        qv * keep,
-        xv,
-        dimension_numbers=(((1,), (1,)), ((), ())),
+        q * keep,
+        xt,
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
-    )  # altitude column zeroed on one side only — enough to drop it
-    z2 = nq + nx.T - 2.0 * dot
+    )  # altitude zeroed on one side only — enough to drop it
+    z2 = nq + nx - 2.0 * dot
     if mode != 0:
-        is_alt = (col == true_k - 1).astype(jnp.float32)
-        qa = jnp.sum(qv * is_alt, axis=1, keepdims=True)  # (bq, 1)
-        xa = jnp.sum(xv * is_alt, axis=1, keepdims=True)  # (bn, 1)
-        cross = 2.0 * qa * xa.T
+        qa = jnp.sum(q * (1.0 - keep), axis=1, keepdims=True)  # (bq, 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (k, 1), 0)
+        xa = jnp.sum(xt * (row == k - 1).astype(jnp.float32), axis=0,
+                     keepdims=True)  # (1, bn)
+        cross = 2.0 * qa * xa
         z2 = z2 - cross if mode == 1 else z2 + cross
     return jnp.sqrt(jnp.maximum(z2, 0.0))
 
@@ -91,6 +95,7 @@ def estimate_rows(
     dot = jnp.einsum(
         "qk,qrk->qr", q[:, :-1], blk[..., :-1],
         preferred_element_type=q.dtype,
+        precision=jax.lax.Precision.HIGHEST,
     )
     z2 = qn + xn - 2.0 * dot
     if mode != 0:
@@ -99,33 +104,36 @@ def estimate_rows(
     return jnp.sqrt(jnp.maximum(z2, 0.0))
 
 
-def lut_estimate_tile(lut: Array, codes: Array) -> Array:
+def lut_estimate_tile(lut: Array, codes_t: Array) -> Array:
     """LUT-gather estimator over one PQ code tile, as seen in a kernel body.
 
     Args:
-      lut:   (M, E) f32 per-(query, cluster) ADC table (``kernels.pq
-             .build_luts``); ``sum_m lut[m, code[m]]`` is the squared
-             estimator distance (mode folding already applied).
-      codes: (rows, M) integer codes of one tile.
+      lut:     (M, E) f32 per-(query, cluster) ADC table (``kernels.pq
+               .build_luts``); ``sum_m lut[m, code[m]]`` is the squared
+               estimator distance (mode folding already applied).
+      codes_t: (M, rows) int32 codes of one tile, transposed (rows on
+               lanes).
 
-    Returns (1, rows) f32 distances. The gather is expressed as a one-hot
-    contraction — ``codes == iota`` mask dotted against the table over both
-    the subspace and entry axes — which lowers to an MXU matmul on TPU
-    (Pallas has no native vector gather from VMEM) and is exact: each row's
-    result is the f32 sum of exactly M table entries, the rest multiply
-    by 0.
+    Returns (1, rows) f32 distances. The gather is expressed per subspace
+    as a one-hot contraction — a (E, rows) ``code == iota`` mask multiplied
+    by that subspace's (1, E) table row — which lowers to an MXU matmul on
+    TPU (Pallas has no native vector gather from VMEM) and is exact: each
+    row's result is the f32 sum of exactly M table entries, the rest
+    multiply by 0.
     """
-    rows, m = codes.shape
+    m, rows = codes_t.shape
     e = lut.shape[1]
-    hot = (codes.astype(jnp.int32)[:, :, None]
-           == jax.lax.broadcasted_iota(jnp.int32, (rows, m, e), 2)
-           ).astype(jnp.float32)
-    z2 = jax.lax.dot_general(
-        hot, lut.astype(jnp.float32),
-        dimension_numbers=(((1, 2), (0, 1)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (rows,)
-    return jnp.sqrt(jnp.maximum(z2, 0.0))[None, :]
+    entry = jax.lax.broadcasted_iota(jnp.int32, (e, rows), 0)
+    z2 = jnp.zeros((1, rows), jnp.float32)
+    for s in range(m):  # M is small and static
+        hot = (codes_t[s:s + 1, :] == entry).astype(jnp.float32)  # (E, rows)
+        z2 = z2 + jax.lax.dot_general(
+            lut[s:s + 1, :], hot,
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )
+    return jnp.sqrt(jnp.maximum(z2, 0.0))
 
 
 def lut_estimate_rows(luts: Array, codes: Array) -> Array:
@@ -170,3 +178,51 @@ def merge_topk(
     cat_i = jnp.concatenate([best_i, jnp.broadcast_to(ids, d.shape)], axis=1)
     neg, pos = jax.lax.top_k(-cat_d, k)
     return -neg, jnp.take_along_axis(cat_i, pos, axis=1)
+
+
+def merge_topk_rounds(
+    best_d: Array, best_i: Array, d: Array, ids: Array, k: int
+) -> Tuple[Array, Array]:
+    """Kernel-body twin of :func:`merge_topk`, built from lane reductions.
+
+    Mosaic has no ``top_k``, so the merge runs ``k`` rounds of masked lane
+    ``min``: round ``r`` picks the smallest (distance, position) pair of the
+    concatenated candidates that comes after round ``r-1``'s pick in that
+    lexicographic order. The selection therefore equals ``lax.top_k`` on the
+    negated distances exactly: ascending, ties to the lowest position, and
+    unfilled slots take the (+inf, -1) state lanes that precede every tile
+    lane.
+
+    ``best_d``/``best_i`` are (Q, w) running state, ``w >= k``; ``d``/``ids``
+    the new (Q, r) candidates (``ids`` may be (1, r)). Returns the new
+    (Q, w) state: lanes ``< k`` hold the best k, lanes ``>= k`` (+inf, -1).
+    """
+    cat_d = jnp.concatenate([best_d, d], axis=1)
+    cat_i = jnp.concatenate([best_i, jnp.broadcast_to(ids, d.shape)], axis=1)
+    pos = jax.lax.broadcasted_iota(jnp.int32, cat_d.shape, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, best_d.shape, 1)
+    past_end = jnp.int32(cat_d.shape[1])
+    no_id = jnp.int32(jnp.iinfo(jnp.int32).min)
+    rows = best_d.shape[0]
+
+    def pick(r, carry):
+        prev_d, prev_p, out_d, out_i = carry
+        after = (cat_d > prev_d) | ((cat_d == prev_d) & (pos > prev_p))
+        m = jnp.min(jnp.where(after, cat_d, jnp.inf), axis=1, keepdims=True)
+        p = jnp.min(jnp.where(after & (cat_d == m), pos, past_end),
+                    axis=1, keepdims=True)
+        # exactly one lane sits at position p
+        i = jnp.max(jnp.where(pos == p, cat_i, no_id), axis=1, keepdims=True)
+        return (m, p, jnp.where(lane == r, m, out_d),
+                jnp.where(lane == r, i, out_i))
+
+    init = (
+        jnp.full((rows, 1), -jnp.inf, jnp.float32),
+        jnp.full((rows, 1), -1, jnp.int32),
+        jnp.full(best_d.shape, jnp.inf, jnp.float32),
+        jnp.full(best_i.shape, -1, jnp.int32),
+    )
+    # int32 bounds keep the round counter int32 when x64 is enabled
+    _, _, out_d, out_i = jax.lax.fori_loop(
+        jnp.int32(0), jnp.int32(k), pick, init)
+    return out_d, out_i

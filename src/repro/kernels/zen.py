@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import compiler_params
 
 Array = jax.Array
 
@@ -84,7 +83,7 @@ def zen_estimate(
         ],
         out_specs=pl.BlockSpec((bn, bm), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Np, Mp), jnp.float32),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
         interpret=interpret,

@@ -11,7 +11,8 @@ running top-k held in VMEM scratch:
 
   best_d, best_i : (bq, kw) scratch, kw = n_neighbors rounded up to a lane
   per tile:        d = estimator(q_block, x_tile)          (bq, bn)
-                   merge = top_k(concat([best, d], axis=1), kw)
+                   merge = n_neighbors rounds of lane-min over
+                           concat([best, d], axis=1)  (== lax.top_k)
 
 Peak per-query state is therefore O(kw + bn) — one tile — independent of N.
 Index row ids are derived in-register from the tile position (``j*bn + iota``)
@@ -20,7 +21,7 @@ are masked to +inf before the merge; padded scratch lanes (kw > n_neighbors)
 start at +inf and can never win.
 
 ``zen_topk_scan`` is the schedule-equivalent jnp fallback for CPU/GPU: a
-``lax.scan`` over index chunks with the same concat + top_k merge — XLA keeps
+``lax.scan`` over index chunks with a concat + top_k merge — XLA keeps
 only one chunk of distances live, giving the same O(chunk) memory bound.
 """
 from __future__ import annotations
@@ -33,10 +34,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import compiler_params
 from .scoring import MODE_IDS as _MODE
 from .scoring import estimate_tile as _estimate_tile
 from .scoring import merge_topk as _merge_topk
+from .scoring import merge_topk_rounds as _merge_topk_rounds
 
 Array = jax.Array
 
@@ -45,13 +46,13 @@ def _topk_kernel(
     q_ref,
     x_ref,
     *rest,
-    true_k: int,
     n_index: int,
     n_index_blocks: int,
+    n_keep: int,
     mode: int,
     has_scale: bool,
 ):
-    # with quantised storage a (bn, 1) per-row scale block rides along
+    # with quantised storage a (1, bn) per-row scale block rides along
     if has_scale:
         s_ref, od_ref, oi_ref, bd_ref, bi_ref = rest
     else:
@@ -64,19 +65,17 @@ def _topk_kernel(
         bd_ref[...] = jnp.full_like(bd_ref, jnp.inf)
         bi_ref[...] = jnp.full_like(bi_ref, -1)
 
-    q = q_ref[...].astype(jnp.float32)  # (bq, kp)
-    x = x_ref[...].astype(jnp.float32)  # (bn, kp)
-    scale = s_ref[...] if has_scale else None  # (bn, 1) dequant factors
-    d = _estimate_tile(
-        q, x, true_k=true_k, mode=mode, scale=scale)  # (bq, bn)
+    q = q_ref[...].astype(jnp.float32)  # (bq, k)
+    xt = x_ref[...].astype(jnp.float32)  # (k, bn): rows on lanes
+    scale = s_ref[...] if has_scale else None  # (1, bn) dequant factors
+    d = _estimate_tile(q, xt, mode=mode, scale=scale)  # (bq, bn)
 
-    bn = x.shape[0]
+    bn = xt.shape[1]
     ids = j * bn + jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1)
     d = jnp.where(ids < n_index, d, jnp.inf)  # mask padded tail rows
 
-    kw = bd_ref.shape[1]
-    bd_ref[...], bi_ref[...] = _merge_topk(
-        bd_ref[...], bi_ref[...], d, ids, kw
+    bd_ref[...], bi_ref[...] = _merge_topk_rounds(
+        bd_ref[...], bi_ref[...], d, ids, n_keep
     )
 
     @pl.when(j == n_index_blocks - 1)
@@ -105,10 +104,11 @@ def zen_topk(
     ``index`` may be stored quantised (bf16: just pass the narrow array;
     int8: also pass the (N, 1) per-row ``scales``) — the tile is dequantised
     in-register right after the VMEM load, so the f32 index never exists and
-    DMA traffic stays at the storage width.
+    DMA traffic stays at the storage width. The kernel streams the index
+    transposed, (k, N) with rows on lanes, so a narrow k is not lane-padded.
 
-    Returns (distances f32, indices int32), each (Q, n_neighbors), rows sorted
-    ascending by distance. Never materialises a (Q, N) matrix.
+    Returns (distances f32, indices int32), each (Q, n_neighbors), rows
+    sorted ascending by distance. Never materialises a (Q, N) matrix.
     """
     q, kdim = queries.shape
     n, kdim2 = index.shape
@@ -118,30 +118,30 @@ def zen_topk(
     bq = min(block_q, _rup(q, 8))
     bn = min(block_n, _rup(n, 128))
     kw = _rup(n_neighbors, 128)  # scratch lane width
-    Qp, Np, Kp = _rup(q, bq), _rup(n, bn), _rup(kdim, 128)
-    Qpad = jnp.pad(queries, ((0, Qp - q), (0, Kp - kdim)))
-    Xpad = jnp.pad(index, ((0, Np - n), (0, Kp - kdim)))
+    Qp, Np = _rup(q, bq), _rup(n, bn)
+    Qpad = jnp.pad(queries, ((0, Qp - q), (0, 0)))
+    Xt = jnp.pad(index.T, ((0, 0), (0, Np - n)))
     n_index_blocks = Np // bn
 
     in_specs = [
-        pl.BlockSpec((bq, Kp), lambda i, j: (i, 0)),
-        pl.BlockSpec((bn, Kp), lambda i, j: (j, 0)),
+        pl.BlockSpec((bq, kdim), lambda i, j: (i, 0)),
+        pl.BlockSpec((kdim, bn), lambda i, j: (0, j)),
     ]
-    operands = [Qpad, Xpad]
+    operands = [Qpad, Xt]
     if scales is not None:
         assert scales.shape == (n, 1), (scales.shape, n)
         # padded rows get scale 0: they dequantise to the origin and are
         # masked by the id bound below anyway
-        operands.append(jnp.pad(scales.astype(jnp.float32),
-                                ((0, Np - n), (0, 0))))
-        in_specs.append(pl.BlockSpec((bn, 1), lambda i, j: (j, 0)))
+        operands.append(jnp.pad(scales.astype(jnp.float32).T,
+                                ((0, 0), (0, Np - n))))
+        in_specs.append(pl.BlockSpec((1, bn), lambda i, j: (0, j)))
 
     out_d, out_i = pl.pallas_call(
         functools.partial(
             _topk_kernel,
-            true_k=kdim,
             n_index=n,
             n_index_blocks=n_index_blocks,
+            n_keep=n_neighbors,
             mode=_MODE[mode],
             has_scale=scales is not None,
         ),
@@ -159,7 +159,7 @@ def zen_topk(
             pltpu.VMEM((bq, kw), jnp.float32),
             pltpu.VMEM((bq, kw), jnp.int32),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -212,7 +212,8 @@ def zen_topk_scan(
                 scales, start, chunk, axis=0).astype(acc)
         xn = jnp.sum(blk * blk, axis=1)  # (chunk,)
         dot = jnp.matmul(
-            queries[:, :-1], blk[:, :-1].T, preferred_element_type=acc
+            queries[:, :-1], blk[:, :-1].T, preferred_element_type=acc,
+            precision=jax.lax.Precision.HIGHEST,
         )
         z2 = qn + xn[None, :] - 2.0 * dot
         if mode_i != 0:

@@ -14,15 +14,10 @@ from __future__ import annotations
 
 import jax
 
-try:  # jax >= 0.5: explicit axis types on Mesh
-    from jax.sharding import AxisType
-except ImportError:  # older jax: every mesh axis is implicitly "auto"
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes, devices):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes, devices=devices)
     return jax.make_mesh(
         shape, axes, devices=devices,
         axis_types=(AxisType.Auto,) * len(axes),

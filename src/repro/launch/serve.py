@@ -48,9 +48,9 @@ query dispatches at bucketed shapes (power-of-two Q, fixed ``n_neighbors``
 menu) so the jit cache stays a handful of entries — and so scheduled,
 cached and direct responses are bit-identical (``tests/test_frontend.py``).
 
-CLI (CPU demo):  PYTHONPATH=src python -m repro.launch.serve --n 20000 --dim \
-                 256 --k 16 --queries 64 [--index ivf --nprobe 8] \
-                 [--checkpoint /tmp/zen.ckpt] [--frontend --cache 1024]
+CLI:  PYTHONPATH=src python -m repro.launch.serve --n 20000 --dim \
+      256 --k 16 --queries 64 [--index ivf --nprobe 8] \
+      [--checkpoint /tmp/zen.ckpt] [--frontend --cache 1024]
 """
 from __future__ import annotations
 
@@ -1190,8 +1190,10 @@ def main() -> None:
 
     import os
 
-    from repro.core import quality
     from repro.data import synthetic as syn
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     key = jax.random.PRNGKey(0)
     corpus = syn.manifold_space(key, args.n, args.dim, args.dim // 8)

@@ -490,9 +490,13 @@ def test_dma_copy_blocks_roundtrip_dtypes():
     from repro.kernels import tile_stage
 
     rng = np.random.default_rng(20)
-    for dtype in (np.float32, np.int32):
+    for dtype in (np.float32, np.int32, np.int8):
         src = rng.normal(size=(5, 4, 8)).astype(dtype)
-        out = tile_stage.dma_copy_blocks(jnp.asarray(src), interpret=True)
+        chunks = tile_stage.to_chunks(src)
+        moved = tile_stage.dma_copy_chunks(jnp.asarray(chunks),
+                                           interpret=True)
+        np.testing.assert_array_equal(np.asarray(moved), chunks)
+        out = tile_stage.from_chunks(moved, src.shape, src.dtype)
         np.testing.assert_array_equal(np.asarray(out), src)
 
 

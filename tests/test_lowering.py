@@ -32,8 +32,6 @@ _SCRIPT = textwrap.dedent("""
             continue
         compiled = plan.lower(mesh).compile()
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):  # older jax: list of per-computation dicts
-            ca = ca[0] if ca else {}
         out.append([arch, shape, "ok", float(ca.get("flops", 0))])
     print("RESULT " + json.dumps(out))
 """)

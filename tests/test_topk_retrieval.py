@@ -38,6 +38,38 @@ def _rand_coords(seed, n, k):
     return jnp.asarray(X)
 
 
+# -- in-kernel merge == lax.top_k merge ----------------------------------------
+
+
+@pytest.mark.parametrize("k,r,ties,n_inf", [
+    (10, 256, False, 0),     # plain
+    (16, 128, True, 0),      # heavy distance ties: lowest position wins
+    (64, 128, True, 100),    # fewer finite candidates than k: (+inf, -1) fill
+    (128, 256, False, 0),    # k == state width
+])
+def test_merge_rounds_equals_top_k_merge(k, r, ties, n_inf):
+    from repro.kernels.scoring import merge_topk, merge_topk_rounds
+
+    rng = np.random.default_rng(k + r)
+    rows, w = 5, 128
+    best_d, best_i = (jnp.full((rows, w), jnp.inf, jnp.float32),
+                      jnp.full((rows, w), -1, jnp.int32))
+    want_d, want_i = best_d[:, :k], best_i[:, :k]
+    for step in range(3):
+        d = rng.integers(0, 6, (rows, r)) if ties else rng.random((rows, r))
+        d = d.astype(np.float32)
+        d[:, r - n_inf:] = np.inf  # masked tail rows keep their ids
+        ids = (step * r + np.arange(r, dtype=np.int32))[None, :]
+        best_d, best_i = merge_topk_rounds(
+            best_d, best_i, jnp.asarray(d), jnp.asarray(ids), k)
+        want_d, want_i = merge_topk(
+            want_d, want_i, jnp.asarray(d), jnp.asarray(ids), k)
+        np.testing.assert_array_equal(np.asarray(best_d[:, :k]), want_d)
+        np.testing.assert_array_equal(np.asarray(best_i[:, :k]), want_i)
+        assert bool(jnp.all(jnp.isinf(best_d[:, k:])))
+        assert bool(jnp.all(best_i[:, k:] == -1))
+
+
 # -- kernel vs dense parity ----------------------------------------------------
 
 SHAPES = [

@@ -34,18 +34,24 @@ from repro.launch.serve import ZenServer, build_index
 
 @contextlib.contextmanager
 def _force_x32():
-    """Pin the golden computations to f32 regardless of ambient config.
+    """Pin the golden computations to f32 and to one PRNG stream.
 
     Some test modules enable ``jax_enable_x64`` globally at import time;
     the golden bits are defined as the serving stack's *default* (x32)
-    numerics, so both generation and replay run under this guard.
+    numerics, so both generation and replay run under this guard. The
+    guard also fixes ``jax_threefry_partitionable`` to False, the stream
+    the committed pivots, codebooks and corpora were drawn from: JAX 0.5
+    changed that flag's default, which redraws every random choice and
+    so every neighbour id, while the serving numerics are unchanged.
     """
-    prev = jax.config.jax_enable_x64
+    prev = (jax.config.jax_enable_x64, jax.config.jax_threefry_partitionable)
     jax.config.update("jax_enable_x64", False)
+    jax.config.update("jax_threefry_partitionable", False)
     try:
         yield
     finally:
-        jax.config.update("jax_enable_x64", prev)
+        jax.config.update("jax_enable_x64", prev[0])
+        jax.config.update("jax_threefry_partitionable", prev[1])
 
 #: golden geometry — small enough to commit, big enough that top-k is
 #: non-trivial (multiple IVF clusters, real neighbour structure)
