@@ -55,6 +55,7 @@ CLI:  PYTHONPATH=src python -m repro.launch.serve --n 20000 --dim \
 from __future__ import annotations
 
 import argparse
+import collections
 import dataclasses
 import functools
 import time
@@ -77,6 +78,8 @@ from repro.kernels.scoring import mask_invalid
 from repro.serving import (
     DEFAULT_NEIGHBOR_MENU, MicroBatchScheduler, bucket_neighbors, bucket_q,
 )
+from repro.serving import tracing
+from repro.serving.stats import LATENCY_WINDOW
 
 Array = jax.Array
 
@@ -657,8 +660,11 @@ class ZenServer:
         self.neighbor_menu = tuple(neighbor_menu)
         self.max_batch = max_batch
         self.cache_size = cache_size
-        self._stats = {"queries": 0, "batches": 0, "latency_s": [],
+        self._stats = {"queries": 0, "batches": 0,
                        "upserts": 0, "deletes": 0}
+        # seconds per query() call, newest LATENCY_WINDOW on a monotonic clock
+        self._latency_s = collections.deque(maxlen=LATENCY_WINDOW)
+        tracing.count_compiles()
         # fault tolerance (enable_fault_tolerance): liveness registry,
         # preemption guard, and the degraded state they currently imply
         self.heartbeats = None
@@ -720,7 +726,8 @@ class ZenServer:
             return (jnp.full((queries.shape[0], n_bucket), jnp.inf,
                              jnp.float32),
                     jnp.full((queries.shape[0], n_bucket), -1, jnp.int32))
-        qp = index.transform.transform(queries)
+        with tracing.span(tracing.PROJECT):
+            qp = index.transform.transform(queries)
         n_fetch = min(width, index.size)
         if index.ivf is not None:
             # mesh-sharded IVF takes the device-resident alive mask; the
@@ -728,39 +735,45 @@ class ZenServer:
             kw = ({"alive": self._alive_mask}
                   if self._alive_mask is not None and index.mesh is not None
                   else {})
-            d, ids = index.ivf.search(
-                qp, n_neighbors=n_fetch,
-                nprobe=self.nprobe, mode=self.mode,
-                force_kernel=self.force_kernel, **kw,
-            )
+            with tracing.span(tracing.SEARCH, index="ivf"):
+                d, ids = index.ivf.search(
+                    qp, n_neighbors=n_fetch,
+                    nprobe=self.nprobe, mode=self.mode,
+                    force_kernel=self.force_kernel, **kw,
+                )
         elif index.mesh is not None:
-            d, ids = retrieval_lib.sharded_knn_search(
-                qp, index.coords,
-                n_neighbors=n_fetch, mode=self.mode,
-                mesh=index.mesh, chunk=self.chunk,
-                force_kernel=self.force_kernel, n_valid=index.n_valid,
-                scales=index.coord_scales, alive=self._alive_mask,
-            )
-            d, ids = self._map_row_ids(d, ids, index)
+            with tracing.span(tracing.SEARCH, index="sharded"):
+                d, ids = retrieval_lib.sharded_knn_search(
+                    qp, index.coords,
+                    n_neighbors=n_fetch, mode=self.mode,
+                    mesh=index.mesh, chunk=self.chunk,
+                    force_kernel=self.force_kernel, n_valid=index.n_valid,
+                    scales=index.coord_scales, alive=self._alive_mask,
+                )
         else:
-            d, ids = zen_lib.knn_search(
-                qp, index.coords,
-                n_neighbors=n_fetch, mode=self.mode,
-                chunk=self.chunk if index.coords.shape[0] > self.chunk
-                else 0,
-                scales=index.coord_scales,
-                force_kernel=self.force_kernel,
-            )
-            d, ids = self._map_row_ids(d, ids, index)
+            with tracing.span(tracing.SEARCH, index="flat"):
+                d, ids = zen_lib.knn_search(
+                    qp, index.coords,
+                    n_neighbors=n_fetch, mode=self.mode,
+                    chunk=self.chunk if index.coords.shape[0] > self.chunk
+                    else 0,
+                    scales=index.coord_scales,
+                    force_kernel=self.force_kernel,
+                )
+        if index.ivf is None:  # IVF search returns external ids itself
+            with tracing.span(tracing.MAP_IDS):
+                d, ids = self._map_row_ids(d, ids, index)
         if self.rerank_factor and index.corpus is not None:
-            d, ids = self._rerank(queries, ids, n_bucket, index)
+            with tracing.span(tracing.RERANK):
+                d, ids = self._rerank(queries, ids, n_bucket, index)
         else:
             d, ids = d[:, :n_bucket], ids[:, :n_bucket]
         if d.shape[1] < n_bucket:
             # fewer live rows than the bucket width: pad to the full bucket
             pad = n_bucket - d.shape[1]
-            d = jnp.pad(d, ((0, 0), (0, pad)), constant_values=jnp.inf)
-            ids = jnp.pad(ids, ((0, 0), (0, pad)), constant_values=-1)
+            with tracing.span(tracing.MAP_IDS):
+                d = jnp.pad(d, ((0, 0), (0, pad)), constant_values=jnp.inf)
+                ids = jnp.pad(ids, ((0, 0), (0, pad)), constant_values=-1)
         return d, ids
 
     def query(self, queries: Array, n_neighbors: int = 10, *,
@@ -779,7 +792,7 @@ class ZenServer:
         Ids are *external* ids (stable across churn and checkpoint reload);
         slots the index cannot fill come back as (+inf, -1).
         """
-        t0 = time.time()
+        t0 = time.perf_counter()
         self.on_tick()  # refresh shard liveness / pending preemption save
         queries = jnp.asarray(queries)
         n_rows = int(queries.shape[0])
@@ -818,7 +831,7 @@ class ZenServer:
             d, ids = d[:n_rows, :n_neighbors], ids[:n_rows, :n_neighbors]
         self._stats["queries"] += n_rows
         self._stats["batches"] += 1
-        self._stats["latency_s"].append(time.time() - t0)
+        self._latency_s.append(time.perf_counter() - t0)
         return d, ids
 
     def _map_row_ids(self, d: Array, ids: Array, index: ZenIndex
@@ -1012,12 +1025,20 @@ class ZenServer:
     def stats(self) -> dict:
         """Serving counters: query/batch totals, latency percentiles, churn.
 
+        ``p50_ms``/``p99_ms`` are over the newest ``LATENCY_WINDOW``
+        ``query()`` calls. ``compiles`` counts the executables JAX built in
+        this process by the serving step that built them
+        (``repro.serving.tracing``: ``zen.project``, ``zen.search``, ...;
+        ``"none"`` outside every step): which step recompiled. The
+        frontend's ``compile_count`` beside it counts distinct dispatch
+        shapes, an upper bound on the query path's compiles.
+
         With a frontend attached, a ``"frontend"`` sub-dict adds the SLO
         instrumentation (p50/p95/p99 request latency, batch occupancy,
-        cache hit rate, compile count, backpressure counters) and a
-        ``"cache"`` sub-dict the LRU state (``repro.serving.stats``).
+        queue wait, cache hit rate, compile count, backpressure counters)
+        and a ``"cache"`` sub-dict the LRU state (``repro.serving.stats``).
         """
-        lat = np.asarray(self._stats["latency_s"] or [0.0])
+        lat = np.asarray(self._latency_s or [0.0])
         out = {
             "queries": self._stats["queries"],
             "batches": self._stats["batches"],
@@ -1025,6 +1046,7 @@ class ZenServer:
             "deletes": self._stats["deletes"],
             "p50_ms": float(np.percentile(lat, 50) * 1e3),
             "p99_ms": float(np.percentile(lat, 99) * 1e3),
+            "compiles": tracing.compiles(),
         }
         if self.heartbeats is not None:
             out["degraded_shards"] = [self._ft_shards[i]

@@ -16,8 +16,11 @@ IVF-probe kernels. This package sits between callers and the index:
     invalidates every stale entry.
   * ``stats.FrontendStats`` carries the SLO instrumentation: p50/p95/p99
     latency, batch occupancy, cache hit rate, dispatch-shape (compile)
-    count, reject-on-full backpressure counters, and replica hot-swap
-    accounting.
+    count, reject-on-full backpressure counters, queue wait, and replica
+    hot-swap accounting.
+  * ``tracing.span`` names each step of the serving path in the
+    profiler's trace (``zen.submit`` ... ``zen.resolve``) and counts
+    compiles by the step that caused them.
   * ``loadgen.run_open_loop`` measures all of it under *offered* load:
     Poisson arrivals at a configured QPS (open-loop — no coordinated
     omission), latency-vs-offered-load curves, p99 under overload with

@@ -29,12 +29,14 @@ of letting the queue grow without limit.
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from . import tracing
 from .cache import LRUCache, query_fingerprint, result_key
 from .stats import FrontendStats
 
@@ -89,9 +91,14 @@ class QueryHandle:
     Rows resolve independently (cache hits immediately, misses when their
     dispatch lands); ``result()`` blocks until every row is filled. The
     buffers are plain numpy so resolution never touches the device.
+    ``request_id`` is the scheduler's sequence number of the request, the
+    ``request`` of its ``zen.submit`` span and one of the ``requests`` of
+    each ``zen.dispatch`` that serves it (``serving.tracing``).
     """
 
-    def __init__(self, n_rows: int, n_neighbors: int, clock):
+    def __init__(self, n_rows: int, n_neighbors: int, clock,
+                 request_id: Optional[int] = None):
+        self.request_id = request_id
         self._d = np.full((n_rows, n_neighbors), np.inf, np.float32)
         self._ids = np.full((n_rows, n_neighbors), -1, np.int32)
         self._remaining = n_rows
@@ -198,6 +205,9 @@ class MicroBatchScheduler:
         self.stats = FrontendStats()
         self._pending: List[_Slot] = []
         self._lock = threading.Lock()
+        # next() on a count is atomic under the GIL: ids need no lock
+        self._request_ids = itertools.count()
+        self._dispatch_ids = itertools.count()
         self._ticker: Optional[threading.Thread] = None
         self._stop = threading.Event()
 
@@ -231,45 +241,52 @@ class MicroBatchScheduler:
         q = np.asarray(queries, np.float32)
         if q.ndim == 1:
             q = q[None, :]
-        handle = QueryHandle(q.shape[0], n_neighbors, self.clock)
-        if q.shape[0] == 0:
+        request_id = next(self._request_ids)
+        with tracing.span(tracing.SUBMIT, request=request_id,
+                          rows=q.shape[0]):
+            handle = QueryHandle(q.shape[0], n_neighbors, self.clock,
+                                 request_id)
+            if q.shape[0] == 0:
+                return handle
+            n_bucket, width = self._geometry(n_neighbors)
+            slots = [
+                _Slot(handle, i, q[i], query_fingerprint(q[i]), n_bucket,
+                      width)
+                for i in range(q.shape[0])
+            ]
+            with self._lock:
+                # every handle/stats/cache mutation happens under the queue
+                # lock: a ticker thread may resolve this handle's uncached
+                # rows the moment they land in _pending, and the row
+                # countdown / counters are not atomic on their own
+                hits = [(s, self.cache.get(self._cache_key(s)))
+                        for s in slots]
+                misses = [s for s, v in hits if v is None]
+                if len(misses) > self.queue_limit:
+                    # a retry can never succeed — don't dress this up as
+                    # transient overload (ZenServer.query routes such
+                    # batches to the direct path instead of submitting them)
+                    self.stats.record_reject(q.shape[0])
+                    raise FrontendOverloadError(
+                        f"request of {len(misses)} uncached rows exceeds "
+                        f"queue_limit={self.queue_limit}; split it or use "
+                        "the direct path (ZenServer.query(..., direct=True))")
+                if len(self._pending) + len(misses) > self.queue_limit:
+                    self.stats.record_reject(q.shape[0])
+                    raise FrontendOverloadError(
+                        f"admission queue full ({len(self._pending)}/"
+                        f"{self.queue_limit} rows pending); retry later or "
+                        "raise queue_limit")
+                self.stats.record_submit(q.shape[0])
+                self.stats.record_cache(len(slots) - len(misses),
+                                        len(misses))
+                for s, value in hits:
+                    if value is not None:
+                        s.handle._fill_row(s.row, *value)
+                if handle.done():
+                    self.stats.record_complete(q.shape[0], handle.latency_s)
+                self._pending.extend(misses)
             return handle
-        n_bucket, width = self._geometry(n_neighbors)
-        slots = [
-            _Slot(handle, i, q[i], query_fingerprint(q[i]), n_bucket, width)
-            for i in range(q.shape[0])
-        ]
-        with self._lock:
-            # every handle/stats/cache mutation happens under the queue
-            # lock: a ticker thread may resolve this handle's uncached
-            # rows the moment they land in _pending, and the row
-            # countdown / counters are not atomic on their own
-            hits = [(s, self.cache.get(self._cache_key(s))) for s in slots]
-            misses = [s for s, v in hits if v is None]
-            if len(misses) > self.queue_limit:
-                # a retry can never succeed — don't dress this up as
-                # transient overload (ZenServer.query routes such batches
-                # to the direct path instead of submitting them)
-                self.stats.record_reject(q.shape[0])
-                raise FrontendOverloadError(
-                    f"request of {len(misses)} uncached rows exceeds "
-                    f"queue_limit={self.queue_limit}; split it or use the "
-                    "direct path (ZenServer.query(..., direct=True))")
-            if len(self._pending) + len(misses) > self.queue_limit:
-                self.stats.record_reject(q.shape[0])
-                raise FrontendOverloadError(
-                    f"admission queue full ({len(self._pending)}/"
-                    f"{self.queue_limit} rows pending); retry later or "
-                    "raise queue_limit")
-            self.stats.record_submit(q.shape[0])
-            self.stats.record_cache(len(slots) - len(misses), len(misses))
-            for s, value in hits:
-                if value is not None:
-                    s.handle._fill_row(s.row, *value)
-            if handle.done():
-                self.stats.record_complete(q.shape[0], handle.latency_s)
-            self._pending.extend(misses)
-        return handle
 
     @property
     def backlog(self) -> int:
@@ -308,57 +325,70 @@ class MicroBatchScheduler:
         self.stats.record_tick()
         if not pending:
             return 0
-        groups: Dict[Tuple[int, int], List[_Slot]] = {}
-        for slot in pending:  # FIFO within each result-shape group
-            groups.setdefault((slot.width, slot.n_bucket), []).append(slot)
-        n_dispatches = 0
-        for (width, n_bucket), slots in groups.items():
-            for lo in range(0, len(slots), self.max_batch):
-                chunk = slots[lo:lo + self.max_batch]
-                try:
-                    self._dispatch(chunk, width, n_bucket)
-                except Exception as exc:  # noqa: BLE001 — fail the waiters,
-                    # not the ticker: the popped slots would otherwise hang
-                    # their callers forever and kill the tick loop
-                    with self._lock:
-                        self.stats.record_failure(len(chunk))
-                        for slot in chunk:
-                            slot.handle._fail(exc)
-                else:  # a raised dispatch issued no kernel — don't count it
-                    n_dispatches += 1
-        return n_dispatches
+        with tracing.span(tracing.TICK, pending=len(pending)):
+            groups: Dict[Tuple[int, int], List[_Slot]] = {}
+            for slot in pending:  # FIFO within each result-shape group
+                groups.setdefault((slot.width, slot.n_bucket),
+                                  []).append(slot)
+            n_dispatches = 0
+            for (width, n_bucket), slots in groups.items():
+                for lo in range(0, len(slots), self.max_batch):
+                    chunk = slots[lo:lo + self.max_batch]
+                    try:
+                        self._dispatch(chunk, width, n_bucket)
+                    except Exception as exc:  # noqa: BLE001 — fail the
+                        # waiters, not the ticker: the popped slots would
+                        # otherwise hang their callers forever and kill the
+                        # tick loop
+                        with self._lock:
+                            self.stats.record_failure(len(chunk))
+                            for slot in chunk:
+                                slot.handle._fail(exc)
+                    else:  # a raised dispatch issued no kernel: not counted
+                        n_dispatches += 1
+            return n_dispatches
 
     def _dispatch(
         self, slots: List[_Slot], width: int, n_bucket: int
     ) -> None:
         """One padded kernel dispatch for ``slots`` (all same geometry)."""
-        rows = np.stack([s.qrow for s in slots])
-        qp = bucket_q(rows.shape[0], self.max_batch)
-        if qp > rows.shape[0]:  # pad with copies of a real row: any valid
-            # vector works, the padding rows are sliced off unobserved
-            pad = np.broadcast_to(rows[0], (qp - rows.shape[0],
-                                            rows.shape[1]))
-            rows = np.concatenate([rows, pad])
-        # one index snapshot for both the compute and the cache keys:
-        # concurrent churn swapping server.index mid-dispatch must not
-        # store pre-churn results under the post-churn generation
-        index = self.server.index
-        d, ids = self.server._query_block(rows, width, n_bucket, index=index)
-        d, ids = np.asarray(d), np.asarray(ids)
-        with self._lock:  # see submit(): handles/stats/cache share the lock
-            self.stats.record_dispatch((qp, width, n_bucket), len(slots), qp)
-            done: List[QueryHandle] = []
-            for i, slot in enumerate(slots):
-                # copies, not views: a row view would pin the whole (Qp,
-                # n_bucket) dispatch arrays in the cache
-                self.cache.put(self._cache_key(slot, index.generation),
-                               (d[i].copy(), ids[i].copy()))
-                slot.handle._fill_row(slot.row, d[i], ids[i])
-                if slot.handle.done() and slot.handle not in done:
-                    done.append(slot.handle)
-            for handle in done:
-                self.stats.record_complete(handle._d.shape[0],
-                                           handle.latency_s)
+        t_start = self.clock()
+        qp = bucket_q(len(slots), self.max_batch)
+        requests = list(dict.fromkeys(s.handle.request_id for s in slots))
+        with tracing.span(tracing.DISPATCH, dispatch=next(self._dispatch_ids),
+                          requests=requests, rows=len(slots), bucket=qp,
+                          width=width):
+            rows = np.stack([s.qrow for s in slots])
+            if qp > rows.shape[0]:  # pad with copies of a real row: any
+                # valid vector works, the padding rows are sliced off unseen
+                pad = np.broadcast_to(rows[0], (qp - rows.shape[0],
+                                                rows.shape[1]))
+                rows = np.concatenate([rows, pad])
+            # one index snapshot for both the compute and the cache keys:
+            # concurrent churn swapping server.index mid-dispatch must not
+            # store pre-churn results under the post-churn generation
+            index = self.server.index
+            d, ids = self.server._query_block(rows, width, n_bucket,
+                                              index=index)
+            with tracing.span(tracing.FETCH):
+                d, ids = np.asarray(d), np.asarray(ids)
+            wait_s = sum(t_start - s.handle._t_submit for s in slots)
+            with tracing.span(tracing.RESOLVE), self._lock:
+                # see submit(): handles/stats/cache share the lock
+                self.stats.record_dispatch((qp, width, n_bucket),
+                                           len(slots), qp, wait_s)
+                done: List[QueryHandle] = []
+                for i, slot in enumerate(slots):
+                    # copies, not views: a row view would pin the whole (Qp,
+                    # n_bucket) dispatch arrays in the cache
+                    self.cache.put(self._cache_key(slot, index.generation),
+                                   (d[i].copy(), ids[i].copy()))
+                    slot.handle._fill_row(slot.row, d[i], ids[i])
+                    if slot.handle.done() and slot.handle not in done:
+                        done.append(slot.handle)
+                for handle in done:
+                    self.stats.record_complete(handle._d.shape[0],
+                                               handle.latency_s)
 
     def flush(self) -> None:
         """Tick until the queue is empty (inline driving, no ticker)."""

@@ -9,6 +9,9 @@ needs to see whether the frontend is earning its keep:
   * **batch occupancy** — real rows per dispatch over the padded bucket
     size; low occupancy means the tick interval is too short or traffic
     too thin for batching to pay;
+  * **queue wait** — for each dispatched row, the time on the scheduler's
+    clock from its request's submit to the start of the dispatch that
+    took it, summed (``queue_wait_s``; ``queue_wait_ms_mean`` per row);
   * **cache hit rate** — forwarded from the LRU projection/result cache;
   * **compile pressure** — the set of distinct dispatch shapes
     ``(Q_bucket, fetch_width, n_bucket)`` seen so far; its size bounds the
@@ -41,6 +44,7 @@ class FrontendStats:
         self.dispatches = 0       # kernel dispatches issued
         self.dispatched_rows = 0  # real rows across all dispatches
         self.padded_rows = 0      # padded (bucketed) rows across dispatches
+        self.queue_wait_s = 0.0   # submit -> dispatch start, summed by row
         self.ticks = 0
         self.swaps = 0            # replica hot-swaps absorbed (replication)
         self.serving_generation = None  # generation after the last swap
@@ -71,12 +75,15 @@ class FrontendStats:
         self.serving_generation = int(generation)
 
     def record_dispatch(
-        self, shape: Tuple[int, int, int], real_rows: int, padded_rows: int
+        self, shape: Tuple[int, int, int], real_rows: int, padded_rows: int,
+        queue_wait_s: float,
     ) -> None:
-        """One kernel dispatch: its bucketed shape and fill level."""
+        """One kernel dispatch: its bucketed shape, fill level, and the
+        summed queue wait of its real rows."""
         self.dispatches += 1
         self.dispatched_rows += real_rows
         self.padded_rows += padded_rows
+        self.queue_wait_s += queue_wait_s
         self.dispatch_shapes.add(shape)
 
     def record_complete(self, rows: int, latency_s: float) -> None:
@@ -89,6 +96,12 @@ class FrontendStats:
         """Mean dispatch fill: real rows / padded bucket rows."""
         return (self.dispatched_rows / self.padded_rows
                 if self.padded_rows else 0.0)
+
+    @property
+    def queue_wait_ms_mean(self) -> float:
+        """Mean wait of a dispatched row from submit to its dispatch, ms."""
+        return (1e3 * self.queue_wait_s / self.dispatched_rows
+                if self.dispatched_rows else 0.0)
 
     @property
     def cache_hit_rate(self) -> float:
@@ -128,6 +141,7 @@ class FrontendStats:
             "ticks": self.ticks,
             "dispatches": self.dispatches,
             "batch_occupancy": round(self.occupancy, 4),
+            "queue_wait_ms_mean": self.queue_wait_ms_mean,
             "cache_hits": self.cache_hits,
             "cache_misses": self.cache_misses,
             "cache_hit_rate": round(self.cache_hit_rate, 4),
