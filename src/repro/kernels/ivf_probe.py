@@ -16,12 +16,17 @@ index maps read ``probes[i, j // T] * T + j % T`` to DMA exactly the probed
 tiles from HBM — un-probed clusters are never touched, which is what makes
 the probe sublinear in index size. Each grid step fuses the Zen/Lwb/Upb
 estimator over one tile (``kernels.scoring.estimate_tile`` — shared with the
-brute-force ``zen_topk`` kernel) with the lane-min merge
+brute-force ``zen_topk`` kernel) with the entrant merge
 (``kernels.scoring.merge_topk_rounds``) into VMEM scratch; dead rows
 (id == -1: tile padding *and* tombstoned deletes — the mutable-index path
 reuses the same encoding, ``kernels.scoring.mask_invalid``) are masked to
 +inf before the merge. Peak per-query state is O(kw + tile_rows),
-independent of both index size and cluster-size skew.
+independent of both index size and cluster-size skew. The merge's cost
+follows the tile's entrants, the candidates that beat the query's current
+k-th best: an all-padding tile, or one whose rows are all farther than the
+running best, costs one compare and one count and no insertion round. A
+(Q, 1, 1) output counts each query's rounds over the grid
+(``return_rounds=True`` returns it).
 
 Mosaic accepts a block only when its last two dims divide by (8, 128) or
 equal the array's, so every per-query operand is a (n, 1, X) view with a
@@ -56,7 +61,7 @@ def _probe_kernel(
     q_ref,       # (1, k)
     x_ref,       # (k, tile_rows) — the probed tile, rows on lanes
     id_ref,      # (1, tile_rows)
-    *rest,       # [s_ref (1, 1)] od_ref oi_ref + scratch bd_ref bi_ref
+    *rest,       # [s_ref (1, 1)] od_ref oi_ref or_ref + scratch bd_ref bi_ref
     n_steps: int,
     n_keep: int,
     mode: int,
@@ -64,9 +69,9 @@ def _probe_kernel(
 ):
     del probes_ref  # only the index maps need it
     if has_scale:  # the probed cluster's dequant scale rides along
-        s_ref, od_ref, oi_ref, bd_ref, bi_ref = rest
+        s_ref, od_ref, oi_ref, or_ref, bd_ref, bi_ref = rest
     else:
-        od_ref, oi_ref, bd_ref, bi_ref = rest
+        od_ref, oi_ref, or_ref, bd_ref, bi_ref = rest
         s_ref = None
     j = pl.program_id(1)
 
@@ -74,6 +79,7 @@ def _probe_kernel(
     def _init():
         bd_ref[...] = jnp.full_like(bd_ref, jnp.inf)
         bi_ref[...] = jnp.full_like(bi_ref, -1)
+        or_ref[...] = jnp.zeros_like(or_ref)
 
     q = q_ref[...].astype(jnp.float32)          # (1, k)
     xt = x_ref[...].astype(jnp.float32)         # (k, tile_rows)
@@ -82,8 +88,9 @@ def _probe_kernel(
     d = estimate_tile(q, xt, mode=mode, scale=scale)  # (1, tile_rows)
     d = mask_invalid(d, ids)                    # padding + tombstones
 
-    bd_ref[...], bi_ref[...] = merge_topk_rounds(
+    bd_ref[...], bi_ref[...], rounds = merge_topk_rounds(
         bd_ref[...], bi_ref[...], d, ids, n_keep)
+    or_ref[...] += rounds
 
     @pl.when(j == n_steps - 1)
     def _done():
@@ -98,19 +105,28 @@ def _row_spec(width: int) -> pl.BlockSpec:
 
 def _probe_outputs(q: int, kw: int):
     """(out_specs, scratch_shapes, out_shape) of a per-query probe: the
-    (Q, 1, kw) output views keep every block's last two dims equal to the
-    array's."""
+    (Q, 1, kw) result views and the (Q, 1, 1) merge-round counts keep every
+    block's last two dims equal to the array's."""
     return (
-        [_row_spec(kw), _row_spec(kw)],
+        [_row_spec(kw), _row_spec(kw), _row_spec(1)],
         [pltpu.VMEM((1, kw), jnp.float32), pltpu.VMEM((1, kw), jnp.int32)],
         [jax.ShapeDtypeStruct((q, 1, kw), jnp.float32),
-         jax.ShapeDtypeStruct((q, 1, kw), jnp.int32)],
+         jax.ShapeDtypeStruct((q, 1, kw), jnp.int32),
+         jax.ShapeDtypeStruct((q, 1, 1), jnp.int32)],
     )
+
+
+def _probe_results(out_d, out_i, rounds, n_neighbors: int,
+                   return_rounds: bool):
+    """A probe's (Q, n_neighbors) results, and its (Q,) rounds if asked."""
+    out = out_d[:, 0, :n_neighbors], out_i[:, 0, :n_neighbors]
+    return (*out, rounds[:, 0, 0]) if return_rounds else out
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("n_neighbors", "mode", "tiles_per_cluster", "interpret"),
+    static_argnames=("n_neighbors", "mode", "tiles_per_cluster", "interpret",
+                     "return_rounds"),
 )
 def ivf_probe(
     queries: Array,
@@ -123,7 +139,8 @@ def ivf_probe(
     tiles_per_cluster: int,
     tile_scales: Optional[Array] = None,
     interpret: bool = False,
-) -> Tuple[Array, Array]:
+    return_rounds: bool = False,
+) -> Tuple[Array, ...]:
     """Clustered top-k probe: score only the tiles of the probed clusters.
 
     Args:
@@ -141,7 +158,9 @@ def ivf_probe(
 
     Returns (distances f32, indices int32), each (Q, n_neighbors), rows
     ascending by distance; slots beyond the number of valid candidates in the
-    probed clusters come back as (+inf, -1).
+    probed clusters come back as (+inf, -1). ``return_rounds`` adds a third
+    element: the (Q,) int32 merge rounds run for each query over its probe
+    (the kernel counts them either way).
     """
     q, kdim = queries.shape
     ct, tile_rows, kdim2 = tile_coords.shape
@@ -174,7 +193,7 @@ def ivf_probe(
         operands.append(tile_scales.astype(jnp.float32).reshape(ct // T, 1, 1))
 
     out_specs, scratch, out_shape = _probe_outputs(q, kw)
-    out_d, out_i = pl.pallas_call(
+    outs = pl.pallas_call(
         functools.partial(
             _probe_kernel, n_steps=n_steps, n_keep=n_neighbors,
             mode=MODE_IDS[mode], has_scale=tile_scales is not None,
@@ -189,7 +208,7 @@ def ivf_probe(
         interpret=interpret,
         name="nsimplex_ivf_probe",
     )(probes.astype(jnp.int32), *operands)
-    return out_d[:, 0, :n_neighbors], out_i[:, 0, :n_neighbors]
+    return _probe_results(*outs, n_neighbors, return_rounds)
 
 
 @functools.partial(
@@ -268,6 +287,7 @@ def _probe_pq_kernel(
     id_ref,      # (1, tile_rows)
     od_ref,
     oi_ref,
+    or_ref,      # (1, 1) int32 merge rounds
     bd_ref,      # scratch (1, kw) f32
     bi_ref,      # scratch (1, kw) int32
     *,
@@ -281,13 +301,15 @@ def _probe_pq_kernel(
     def _init():
         bd_ref[...] = jnp.full_like(bd_ref, jnp.inf)
         bi_ref[...] = jnp.full_like(bi_ref, -1)
+        or_ref[...] = jnp.zeros_like(or_ref)
 
     ids = id_ref[...]                            # (1, tile_rows)
     d = lut_estimate_tile(lut_ref[...], x_ref[...])  # (1, tile_rows)
     d = mask_invalid(d, ids)                     # padding + tombstones
 
-    bd_ref[...], bi_ref[...] = merge_topk_rounds(
+    bd_ref[...], bi_ref[...], rounds = merge_topk_rounds(
         bd_ref[...], bi_ref[...], d, ids, n_keep)
+    or_ref[...] += rounds
 
     @pl.when(j == n_steps - 1)
     def _done():
@@ -297,7 +319,8 @@ def _probe_pq_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("n_neighbors", "tiles_per_cluster", "interpret"),
+    static_argnames=("n_neighbors", "tiles_per_cluster", "interpret",
+                     "return_rounds"),
 )
 def ivf_probe_pq(
     tile_codes: Array,
@@ -308,7 +331,8 @@ def ivf_probe_pq(
     *,
     tiles_per_cluster: int,
     interpret: bool = False,
-) -> Tuple[Array, Array]:
+    return_rounds: bool = False,
+) -> Tuple[Array, ...]:
     """Clustered top-k probe over PQ code tiles with fused LUT scoring.
 
     Args:
@@ -329,7 +353,8 @@ def ivf_probe_pq(
     Returns (distances f32, indices int32), each (Q, n_neighbors),
     ascending; unfilled slots are (+inf, -1). Distances equal the estimator
     on the *decoded* member coordinates — the mode folding happened in the
-    tables.
+    tables. ``return_rounds`` adds the (Q,) merge rounds, as in
+    :func:`ivf_probe`.
     """
     ct, tile_rows, m = tile_codes.shape
     q, n_probe = probes.shape
@@ -349,7 +374,7 @@ def ivf_probe_pq(
         return (pref[i, j // T] * T + j % T, 0, 0)
 
     out_specs, scratch, out_shape = _probe_outputs(q, kw)
-    out_d, out_i = pl.pallas_call(
+    outs = pl.pallas_call(
         functools.partial(_probe_pq_kernel, n_steps=n_steps,
                           n_keep=n_neighbors),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -373,7 +398,7 @@ def ivf_probe_pq(
     )(probes.astype(jnp.int32), luts3,
       jnp.swapaxes(tile_codes, 1, 2).astype(jnp.int32),
       tile_ids.reshape(ct, 1, tile_rows))
-    return out_d[:, 0, :n_neighbors], out_i[:, 0, :n_neighbors]
+    return _probe_results(*outs, n_neighbors, return_rounds)
 
 
 @functools.partial(

@@ -16,7 +16,10 @@ operates on transposed (k, rows) tiles as seen inside a Pallas kernel body;
 where every query gathers its *own* (rows, k) tile. The scans merge with
 ``merge_topk`` (concat + ``lax.top_k``); Mosaic cannot lower ``top_k``, so
 the kernels merge with ``merge_topk_rounds``, which selects exactly the same
-entries in the same order.
+entries in the same order. Its cost follows the tile's *entrants*, the
+candidates that beat their row's current k-th best: one compare and one
+count per tile, then one insertion round per entrant of the busiest row
+(at most k), and none once the running best has settled.
 
 Both accept an optional ``scale`` for quantised index tiles
 (``kernels.quantize``): the tile is multiplied by its symmetric int8 scale
@@ -182,47 +185,64 @@ def merge_topk(
 
 def merge_topk_rounds(
     best_d: Array, best_i: Array, d: Array, ids: Array, k: int
-) -> Tuple[Array, Array]:
-    """Kernel-body twin of :func:`merge_topk`, built from lane reductions.
+) -> Tuple[Array, Array, Array]:
+    """Kernel-body twin of :func:`merge_topk` whose work follows the tile's
+    entrants, built from lane reductions (Mosaic has no ``top_k``).
 
-    Mosaic has no ``top_k``, so the merge runs ``k`` rounds of masked lane
-    ``min``: round ``r`` picks the smallest (distance, position) pair of the
-    concatenated candidates that comes after round ``r-1``'s pick in that
-    lexicographic order. The selection therefore equals ``lax.top_k`` on the
-    negated distances exactly: ascending, ties to the lowest position, and
-    unfilled slots take the (+inf, -1) state lanes that precede every tile
-    lane.
+    A candidate is an *entrant* if its distance is strictly below its row's
+    current k-th best: the state lanes precede the tile lanes in
+    :func:`merge_topk`'s concatenation, so a tie at the k-th distance keeps
+    the state entry, and a masked (+inf) candidate never enters. One compare
+    and one lane sum count each row's entrants; the merge then runs
+    ``c = min(k, most entrants of any row)`` rounds, none when no row has
+    one. Round ``r`` takes each row's ``r``-th smallest entrant in
+    (distance, lane) order and inserts it into the sorted state at
+    ``count(state <= v)``, after every equal entry, shifting the later
+    lanes by one. Only a row's k smallest entrants can survive, so
+    ``c`` rounds suffice. The selection equals ``lax.top_k`` on the negated
+    concatenation exactly: ascending, ties to the lowest position, and
+    unfilled slots keep the (+inf, -1) state lanes.
 
-    ``best_d``/``best_i`` are (Q, w) running state, ``w >= k``; ``d``/``ids``
-    the new (Q, r) candidates (``ids`` may be (1, r)). Returns the new
-    (Q, w) state: lanes ``< k`` hold the best k, lanes ``>= k`` (+inf, -1).
+    ``best_d``/``best_i`` are (Q, w) running state, ``w >= k``, ascending,
+    lanes ``>= k`` (+inf, -1); ``d``/``ids`` the new (Q, r) candidates
+    (``ids`` may be (1, r)). Returns the new (Q, w) state in the same form
+    and ``c``, the int32 number of rounds run.
     """
-    cat_d = jnp.concatenate([best_d, d], axis=1)
-    cat_i = jnp.concatenate([best_i, jnp.broadcast_to(ids, d.shape)], axis=1)
-    pos = jax.lax.broadcasted_iota(jnp.int32, cat_d.shape, 1)
+    pos = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
     lane = jax.lax.broadcasted_iota(jnp.int32, best_d.shape, 1)
-    past_end = jnp.int32(cat_d.shape[1])
+    kth = jnp.min(jnp.where(lane == k - 1, best_d, jnp.inf), axis=1,
+                  keepdims=True)  # a lane slice at k-1 is off the tiling
+    entrant = d < kth
+    counts = jnp.sum(entrant, axis=1, keepdims=True, dtype=jnp.int32)
+    rounds = jnp.minimum(jnp.max(counts), k)
+    past_end = jnp.int32(d.shape[1])
     no_id = jnp.int32(jnp.iinfo(jnp.int32).min)
     rows = best_d.shape[0]
 
-    def pick(r, carry):
+    def insert(r, carry):
         prev_d, prev_p, out_d, out_i = carry
-        after = (cat_d > prev_d) | ((cat_d == prev_d) & (pos > prev_p))
-        m = jnp.min(jnp.where(after, cat_d, jnp.inf), axis=1, keepdims=True)
-        p = jnp.min(jnp.where(after & (cat_d == m), pos, past_end),
+        after = entrant & ((d > prev_d) | ((d == prev_d) & (pos > prev_p)))
+        v = jnp.min(jnp.where(after, d, jnp.inf), axis=1, keepdims=True)
+        p = jnp.min(jnp.where(after & (d == v), pos, past_end),
                     axis=1, keepdims=True)
-        # exactly one lane sits at position p
-        i = jnp.max(jnp.where(pos == p, cat_i, no_id), axis=1, keepdims=True)
-        return (m, p, jnp.where(lane == r, m, out_d),
-                jnp.where(lane == r, i, out_i))
+        # exactly one lane sits at position p; a row out of entrants has
+        # v = +inf, which lands past every lane and changes nothing
+        i = jnp.max(jnp.where(pos == p, ids, no_id), axis=1, keepdims=True)
+        at = jnp.sum(out_d <= v, axis=1, keepdims=True, dtype=jnp.int32)
+        out_d = jnp.where(lane < at, out_d,
+                          jnp.where(lane == at, v, jnp.roll(out_d, 1, 1)))
+        out_i = jnp.where(lane < at, out_i,
+                          jnp.where(lane == at, i, jnp.roll(out_i, 1, 1)))
+        return (v, p, jnp.where(lane < k, out_d, jnp.inf),
+                jnp.where(lane < k, out_i, -1))
 
     init = (
         jnp.full((rows, 1), -jnp.inf, jnp.float32),
         jnp.full((rows, 1), -1, jnp.int32),
-        jnp.full(best_d.shape, jnp.inf, jnp.float32),
-        jnp.full(best_i.shape, -1, jnp.int32),
+        best_d,
+        best_i,
     )
     # int32 bounds keep the round counter int32 when x64 is enabled
     _, _, out_d, out_i = jax.lax.fori_loop(
-        jnp.int32(0), jnp.int32(k), pick, init)
-    return out_d, out_i
+        jnp.int32(0), rounds, insert, init)
+    return out_d, out_i, rounds

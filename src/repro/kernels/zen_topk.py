@@ -11,14 +11,22 @@ running top-k held in VMEM scratch:
 
   best_d, best_i : (bq, kw) scratch, kw = n_neighbors rounded up to a lane
   per tile:        d = estimator(q_block, x_tile)          (bq, bn)
-                   merge = n_neighbors rounds of lane-min over
-                           concat([best, d], axis=1)  (== lax.top_k)
+                   entrants = d < each row's n_neighbors-th best
+                   merge = one sorted insertion per entrant of the block's
+                           busiest row, at most n_neighbors rounds and none
+                           for a tile without entrants  (== lax.top_k of
+                           concat([best, d], axis=1))
 
 Peak per-query state is therefore O(kw + bn) — one tile — independent of N.
 Index row ids are derived in-register from the tile position (``j*bn + iota``)
 so no id tensor is streamed either. Padded tail rows (N not a multiple of bn)
 are masked to +inf before the merge; padded scratch lanes (kw > n_neighbors)
 start at +inf and can never win.
+
+The merge's cost follows the entrants, not ``n_neighbors``: once the
+running best has settled, most tiles cost one compare and one count. A
+small (bq, 1) output counts, per query row, the rounds run over the grid
+(``return_rounds=True`` returns it).
 
 ``zen_topk_scan`` is the schedule-equivalent jnp fallback for CPU/GPU: a
 ``lax.scan`` over index chunks with a concat + top_k merge — XLA keeps
@@ -54,9 +62,9 @@ def _topk_kernel(
 ):
     # with quantised storage a (1, bn) per-row scale block rides along
     if has_scale:
-        s_ref, od_ref, oi_ref, bd_ref, bi_ref = rest
+        s_ref, od_ref, oi_ref, or_ref, bd_ref, bi_ref = rest
     else:
-        od_ref, oi_ref, bd_ref, bi_ref = rest
+        od_ref, oi_ref, or_ref, bd_ref, bi_ref = rest
         s_ref = None
     j = pl.program_id(1)
 
@@ -64,6 +72,7 @@ def _topk_kernel(
     def _init():
         bd_ref[...] = jnp.full_like(bd_ref, jnp.inf)
         bi_ref[...] = jnp.full_like(bi_ref, -1)
+        or_ref[...] = jnp.zeros_like(or_ref)
 
     q = q_ref[...].astype(jnp.float32)  # (bq, k)
     xt = x_ref[...].astype(jnp.float32)  # (k, bn): rows on lanes
@@ -74,9 +83,10 @@ def _topk_kernel(
     ids = j * bn + jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1)
     d = jnp.where(ids < n_index, d, jnp.inf)  # mask padded tail rows
 
-    bd_ref[...], bi_ref[...] = _merge_topk_rounds(
+    bd_ref[...], bi_ref[...], rounds = _merge_topk_rounds(
         bd_ref[...], bi_ref[...], d, ids, n_keep
     )
+    or_ref[...] += rounds
 
     @pl.when(j == n_index_blocks - 1)
     def _done():
@@ -86,7 +96,8 @@ def _topk_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("n_neighbors", "mode", "block_q", "block_n", "interpret"),
+    static_argnames=("n_neighbors", "mode", "block_q", "block_n", "interpret",
+                     "return_rounds"),
 )
 def zen_topk(
     queries: Array,
@@ -98,7 +109,8 @@ def zen_topk(
     block_q: int = 256,
     block_n: int = 512,
     interpret: bool = False,
-) -> Tuple[Array, Array]:
+    return_rounds: bool = False,
+) -> Tuple[Array, ...]:
     """Streaming top-k under an estimator: (Q, k) x (N, k) -> (Q, n), (Q, n).
 
     ``index`` may be stored quantised (bf16: just pass the narrow array;
@@ -109,6 +121,8 @@ def zen_topk(
 
     Returns (distances f32, indices int32), each (Q, n_neighbors), rows
     sorted ascending by distance. Never materialises a (Q, N) matrix.
+    ``return_rounds`` adds a third element: the (Q,) int32 merge rounds run
+    for each query over the whole scan (the kernel counts them either way).
     """
     q, kdim = queries.shape
     n, kdim2 = index.shape
@@ -136,7 +150,7 @@ def zen_topk(
                                 ((0, 0), (0, Np - n))))
         in_specs.append(pl.BlockSpec((1, bn), lambda i, j: (0, j)))
 
-    out_d, out_i = pl.pallas_call(
+    out_d, out_i, rounds = pl.pallas_call(
         functools.partial(
             _topk_kernel,
             n_index=n,
@@ -150,10 +164,12 @@ def zen_topk(
         out_specs=[
             pl.BlockSpec((bq, kw), lambda i, j: (i, 0)),
             pl.BlockSpec((bq, kw), lambda i, j: (i, 0)),
+            pl.BlockSpec((bq, 1), lambda i, j: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Qp, kw), jnp.float32),
             jax.ShapeDtypeStruct((Qp, kw), jnp.int32),
+            jax.ShapeDtypeStruct((Qp, 1), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, kw), jnp.float32),
@@ -165,7 +181,8 @@ def zen_topk(
         interpret=interpret,
         name="nsimplex_zen_topk",
     )(*operands)
-    return out_d[:q, :n_neighbors], out_i[:q, :n_neighbors]
+    out = out_d[:q, :n_neighbors], out_i[:q, :n_neighbors]
+    return (*out, rounds[:q, 0]) if return_rounds else out
 
 
 @functools.partial(

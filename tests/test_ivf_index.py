@@ -167,6 +167,38 @@ def test_probe_kernel_matches_scan(n, k, c, nprobe, mode):
     assert (np.asarray(kern_i) == np.asarray(scan_i)).all()
 
 
+def test_probe_kernel_rounds_skip_padding_tiles():
+    """An all-padding tile runs no merge round: each query probes its own
+    near cluster (30 members, then an all-padding second tile) and a far
+    one. With 9 neighbours the far members cannot enter (9 rounds); with
+    40 the state is not full, so all 60 members enter (60 rounds). Both
+    equal the scan."""
+    rng = np.random.default_rng(11)
+    c, t, rows, k = 6, 2, 128, 12
+    coords = np.zeros((c * t, rows, k), np.float32)
+    ids = np.full((c * t, rows), -1, np.int32)
+    for cl in range(c):
+        coords[cl * t, :30] = cl * 40.0 + rng.normal(size=(30, k))
+        ids[cl * t, :30] = cl * 30 + np.arange(30)
+    coords[..., -1] = np.abs(coords[..., -1])
+    Q = jnp.asarray(coords[::t, 0] + 0.01 * rng.normal(size=(c, k)),
+                    jnp.float32)
+    probes = jnp.asarray(
+        np.stack([np.arange(c), (np.arange(c) + 3) % c], axis=1), jnp.int32)
+    args = (Q, jnp.asarray(coords), jnp.asarray(ids), probes)
+    for n_neighbors, want_rounds in ((9, 9), (40, 60)):
+        d, i, rounds = ip.ivf_probe(
+            *args, n_neighbors, "zen", tiles_per_cluster=t, interpret=True,
+            return_rounds=True)
+        want_d, want_i = ip.ivf_probe_scan(
+            *args, n_neighbors, "zen", tiles_per_cluster=t)
+        np.testing.assert_array_equal(np.asarray(i), np.asarray(want_i))
+        np.testing.assert_allclose(np.asarray(d), np.asarray(want_d),
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_array_equal(
+            np.asarray(rounds), np.full(c, want_rounds))
+
+
 def test_probe_multi_tile_cluster_layout():
     # force T > 1 and verify against brute force over the probed clusters
     X = _coords(40, 1000, 6)

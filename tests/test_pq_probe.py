@@ -76,12 +76,17 @@ def test_pq_probe_kernel_matches_scan(n, k, c, nprobe, mode):
     scan_d, scan_i = ip.ivf_probe_pq_scan(
         idx.tile_coords, idx.tile_ids, probes, luts, 9,
         tiles_per_cluster=idx.tiles_per_cluster)
-    kern_d, kern_i = ip.ivf_probe_pq(
+    kern_d, kern_i, rounds = ip.ivf_probe_pq(
         idx.tile_coords, idx.tile_ids, probes, luts, 9,
-        tiles_per_cluster=idx.tiles_per_cluster, interpret=True)
+        tiles_per_cluster=idx.tiles_per_cluster, interpret=True,
+        return_rounds=True)
     assert (np.asarray(kern_i) == np.asarray(scan_i)).all()
     np.testing.assert_allclose(np.asarray(kern_d), np.asarray(scan_d),
                                rtol=1e-5, atol=1e-5)
+    # every filled slot entered in some round; no step runs more than 9
+    rounds = np.asarray(rounds)
+    assert (rounds >= np.isfinite(np.asarray(kern_d)).sum(axis=1)).all()
+    assert (rounds <= 9 * nprobe * idx.tiles_per_cluster).all()
 
 
 @pytest.mark.parametrize("mode", MODES)

@@ -41,13 +41,50 @@ def _rand_coords(seed, n, k):
 # -- in-kernel merge == lax.top_k merge ----------------------------------------
 
 
-@pytest.mark.parametrize("k,r,ties,n_inf", [
+def _merge_tile(tiles, rng, step, want_d, r, n_inf):
+    """One (rows, r) f32 tile of distances for the merge cases; ``want_d``
+    is the reference (rows, k) state the tile merges into."""
+    rows, k = want_d.shape
+    if tiles is True:    # heavy distance ties: lowest position wins
+        d = rng.integers(0, 6, (rows, r)).astype(np.float32)
+    elif tiles == "settled" and step:  # no candidate beats any k-th best
+        d = 1.0 + rng.random((rows, r)).astype(np.float32)
+    elif tiles == "one" and step:      # a single entrant in the block
+        d = 1.0 + rng.random((rows, r)).astype(np.float32)
+        d[2, 5] = np.float32(want_d[2, k - 1]) - 0.25
+    elif tiles == "kth" and step:      # many ties at each row's k-th best
+        d = want_d[:, rng.integers(0, k, r)]
+        d[:, ::3] = want_d[:, k - 1:k]
+    elif tiles == "inf":               # every candidate masked
+        d = np.full((rows, r), np.inf, np.float32)
+    elif tiles == "partial" and not step:  # the state fills part-way first
+        d = np.full((rows, r), np.inf, np.float32)
+        d[:, :k // 3] = rng.random((rows, k // 3))
+    elif tiles == "mixed" and step:    # row m has m * k / 8 entrants
+        d = 1.0 + rng.random((rows, r)).astype(np.float32)
+        for m in range(rows):
+            d[m, :m * k // 8] = rng.random(m * k // 8) * want_d[m, k - 1]
+    else:                # uniform distances
+        d = rng.random((rows, r)).astype(np.float32)
+    d = d.astype(np.float32)
+    if n_inf:
+        d[:, r - n_inf:] = np.inf  # masked tail rows keep their ids
+    return d
+
+
+@pytest.mark.parametrize("k,r,tiles,n_inf", [
     (10, 256, False, 0),     # plain
     (16, 128, True, 0),      # heavy distance ties: lowest position wins
     (64, 128, True, 100),    # fewer finite candidates than k: (+inf, -1) fill
     (128, 256, False, 0),    # k == state width
+    (64, 512, "settled", 0),  # a tile with no entrant: no round
+    (64, 128, "one", 0),     # a tile with exactly one entrant: one round
+    (16, 256, "kth", 0),     # ties exactly at the k-th distance stay out
+    (10, 128, "inf", 0),     # all-+inf tiles into an empty state
+    (64, 256, "partial", 0),  # a partly filled state takes every finite
+    (64, 512, "mixed", 0),   # rows of one block with different counts
 ])
-def test_merge_rounds_equals_top_k_merge(k, r, ties, n_inf):
+def test_merge_rounds_equals_top_k_merge(k, r, tiles, n_inf):
     from repro.kernels.scoring import merge_topk, merge_topk_rounds
 
     rng = np.random.default_rng(k + r)
@@ -56,11 +93,10 @@ def test_merge_rounds_equals_top_k_merge(k, r, ties, n_inf):
                       jnp.full((rows, w), -1, jnp.int32))
     want_d, want_i = best_d[:, :k], best_i[:, :k]
     for step in range(3):
-        d = rng.integers(0, 6, (rows, r)) if ties else rng.random((rows, r))
-        d = d.astype(np.float32)
-        d[:, r - n_inf:] = np.inf  # masked tail rows keep their ids
+        d = _merge_tile(tiles, rng, step, np.asarray(want_d), r, n_inf)
+        entrants = (d < np.asarray(want_d)[:, k - 1:k]).sum(axis=1)
         ids = (step * r + np.arange(r, dtype=np.int32))[None, :]
-        best_d, best_i = merge_topk_rounds(
+        best_d, best_i, rounds = merge_topk_rounds(
             best_d, best_i, jnp.asarray(d), jnp.asarray(ids), k)
         want_d, want_i = merge_topk(
             want_d, want_i, jnp.asarray(d), jnp.asarray(ids), k)
@@ -68,6 +104,34 @@ def test_merge_rounds_equals_top_k_merge(k, r, ties, n_inf):
         np.testing.assert_array_equal(np.asarray(best_i[:, :k]), want_i)
         assert bool(jnp.all(jnp.isinf(best_d[:, k:])))
         assert bool(jnp.all(best_i[:, k:] == -1))
+        assert int(rounds) == min(k, entrants.max()), (step, entrants)
+    if tiles == "settled":
+        assert entrants.max() == 0
+    if tiles == "one":
+        assert entrants.max() == 1 and int(rounds) == 1
+    if tiles == "mixed":
+        assert len(set(entrants.tolist())) == rows and int(rounds) < k
+
+
+def test_kernel_rounds_follow_entrants():
+    """The flat kernel merges only entrants: a first tile holding every
+    query's nearest rows takes min(k, tile) rounds and the far tiles after
+    it none, with results equal to the scan fallback."""
+    rng = np.random.default_rng(3)
+    near = rng.normal(size=(128, 12)).astype(np.float32)
+    far = 50.0 + rng.normal(size=(4 * 128 + 44, 12)).astype(np.float32)
+    X = np.concatenate([near, far])
+    X[:, -1] = np.abs(X[:, -1])
+    Q = jnp.asarray(near[:9] + 0.01 * rng.normal(size=(9, 12)), jnp.float32)
+    X = jnp.asarray(X)
+    d, i, rounds = zt.zen_topk(Q, X, 10, "zen", block_q=8, block_n=128,
+                               interpret=True, return_rounds=True)
+    want_d, want_i = zt.zen_topk_scan(Q, X, 10, "zen", chunk=128)
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(want_i))
+    np.testing.assert_allclose(np.asarray(d), np.asarray(want_d),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(rounds), np.full(9, 10))
+    assert rounds.dtype == jnp.int32
 
 
 # -- kernel vs dense parity ----------------------------------------------------

@@ -31,7 +31,7 @@ Q = 64                      # queries per dispatch
 FETCH = 64                  # bucketed candidate width (10 x rerank 4 -> 64)
 TILE_ROWS = 128
 N_CLUSTERS = int(round(4 * N_ROWS ** 0.5))
-TILES_PER_CLUSTER = 8       # ~790 members per cluster on average
+TILES_PER_CLUSTER = 12      # the served layout: C*T = 151,788 tiles
 NPROBE = 8
 PQ_M = 4                    # kernels.pq.default_m(16)
 
